@@ -35,8 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _matmul_atom_kernel(a_ref, b_ref, c_in_ref, c_ref, acc_ref, *, nk: int):
     """One (tile, k) grid step: accumulate a_tile @ b_tile into acc scratch."""
@@ -91,7 +89,7 @@ def matmul_atom(a: jax.Array, b: jax.Array, c: jax.Array, *, start: int,
         out_shape=jax.ShapeDtypeStruct((M, N), c.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         input_output_aliases={2: 0},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(a, b, c)

@@ -14,7 +14,9 @@ for the decode hot loop (each row is one "thread block": it touches its own
 KV stripe only, so disjoint row ranges compose exactly).
 
 The running output is passed in and aliased (``input_output_aliases``) so
-rows outside the atom pass through untouched.
+rows outside the atom pass through untouched.  The whole ``len`` vector is a
+scalar-prefetch operand in SMEM: a per-row ``(1, 1)`` block over ``[R, 1]``
+breaks the TPU's (8, 128) block-tiling rule and is refused by its compiler.
 
 Memory behaviour: decode attention is HBM-bound (reads S*D keys+values per
 row for O(S*D) flops); the kernel streams KV through VMEM in (block_k, D)
@@ -30,15 +32,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
 def _decode_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_in_ref, o_ref,
-                        m_ref, l_ref, acc_ref, *, nk: int, block_k: int,
-                        sm_scale: float):
-    k_idx = pl.program_id(1)
+                        m_ref, l_ref, acc_ref, *, start: int, nk: int,
+                        block_k: int, sm_scale: float):
+    row, k_idx = pl.program_id(0), pl.program_id(1)
 
     @pl.when(k_idx == 0)
     def _init():
@@ -54,7 +54,7 @@ def _decode_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_in_ref, o_ref,
     s = s * sm_scale                                   # [G, block_k]
     kpos = k_idx * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_k), 1)
-    valid = kpos < len_ref[0]
+    valid = kpos < len_ref[start + row]
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]                                # [G, 1]
@@ -87,27 +87,30 @@ def decode_attention_atom(q, k, v, lens, o, *, start: int, num_rows: int,
     nk = S // block_k
     sm_scale = 1.0 / (D ** 0.5)
 
-    kernel = functools.partial(_decode_attn_kernel, nk=nk, block_k=block_k,
-                               sm_scale=sm_scale)
-    lens2 = lens.reshape(R, 1)
+    kernel = functools.partial(_decode_attn_kernel, start=start, nk=nk,
+                               block_k=block_k, sm_scale=sm_scale)
+    # index maps take the prefetched lens ref as a trailing argument
     return pl.pallas_call(
         kernel,
-        grid=(num_rows, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda r, k: (start + r, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, G, D), lambda r, k: (start + r, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda r, k: (start + r, k, 0)),
-            pl.BlockSpec((1, block_k, D), lambda r, k: (start + r, k, 0)),
-            pl.BlockSpec((1, G, D), lambda r, k: (start + r, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, G, D), lambda r, k: (start + r, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(num_rows, nk),
+            in_specs=[
+                pl.BlockSpec((1, G, D), lambda r, k, _: (start + r, 0, 0)),
+                pl.BlockSpec((1, block_k, D),
+                             lambda r, k, _: (start + r, k, 0)),
+                pl.BlockSpec((1, block_k, D),
+                             lambda r, k, _: (start + r, k, 0)),
+                pl.BlockSpec((1, G, D), lambda r, k, _: (start + r, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, G, D),
+                                   lambda r, k, _: (start + r, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((G, 1), jnp.float32),
+                            pltpu.VMEM((G, 1), jnp.float32),
+                            pltpu.VMEM((G, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((R, G, D), o.dtype),
-        scratch_shapes=[pltpu.VMEM((G, 1), jnp.float32),
-                        pltpu.VMEM((G, 1), jnp.float32),
-                        pltpu.VMEM((G, D), jnp.float32)],
         input_output_aliases={4: 0},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(lens2, q, k, v, o)
+    )(lens, q, k, v, o)

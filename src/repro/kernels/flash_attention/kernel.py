@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -131,7 +129,7 @@ def flash_attention_atom(q, k, v, o, *, start: int, num_tiles: int,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         input_output_aliases={3: 0},
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(q, k, v, o)

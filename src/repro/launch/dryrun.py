@@ -159,10 +159,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
         t_compile = time.time() - t1
 
     mem = compiled.memory_analysis()
-    # cost_analysis() returns a list of per-program dicts on jax 0.4.3x and
-    # a plain dict on older versions — normalize before .get() below.
-    from repro.roofline.hlo_cost import xla_cost_dict
-    cost = xla_cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     ops = count_ops(hlo)
 

@@ -19,7 +19,13 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto(axes) -> tuple:
+    """Auto axis types: the sharding rules here are GSPMD constraints, and
+    ``jax.make_mesh`` otherwise makes every axis Explicit."""
+    return (AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -28,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     n = math.prod(shape)
     devs = jax.devices()
     if len(devs) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, _auto(axes))
     assert len(devs) >= n, (f"need {n} devices for the production mesh; "
                             f"have {len(devs)} — is XLA_FLAGS set?")
     # dry-run process exposes 512 placeholder devices; the single-pod mesh
@@ -39,8 +45,8 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
     """Elastic variant: any (pods, data, model) factorization of the
     available device count (used by the elastic-scaling tests)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, _auto(axes))
 
 
 def single_device_mesh() -> Mesh:
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

@@ -95,6 +95,8 @@ def main(argv=None):
     if args.ctl_state_dir is not None:
         print(submit_to_ctl(args))
         return
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -26,6 +26,7 @@ from repro.configs.registry import ARCH_IDS, get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed.coordinator import Coordinator, CoordinatorConfig
 from repro.launch import shardings as shlib
+from repro.launch.cache import use_compile_cache
 from repro.models.sharding import use_mesh
 from repro.train.step import TrainConfig, TrainState, make_train_step
 
@@ -39,26 +40,27 @@ def train(cfg, *, steps: int = 50, batch: int = 8, seq: int = 128,
     tc = tc or TrainConfig(total_steps=steps, warmup_steps=max(1, steps // 10))
     init_state, train_step = make_train_step(cfg, tc)
 
+    key = jax.random.PRNGKey(seed)
+    template = jax.eval_shape(init_state, key)
+    state_sh = (shlib.train_state_shardings(template, cfg, mesh)
+                if mesh is not None else None)
     mgr = CheckpointManager(ckpt_dir) if ckpt_dir else None
-    state = None
     start_step = 0
     if mgr and latest_step(ckpt_dir) is not None:
-        template = jax.eval_shape(init_state, jax.random.PRNGKey(seed))
         state = mgr.restore(template)
+        if mesh is not None:
+            state = jax.device_put(state, state_sh)
         start_step = int(np.asarray(state.opt.step))
         if verbose:
             print(f"[train] restored checkpoint at step {start_step}")
-    if state is None:
-        state = init_state(jax.random.PRNGKey(seed))
-
-    if mesh is not None:
-        state_sh = shlib.train_state_shardings(
-            jax.eval_shape(init_state, jax.random.PRNGKey(seed)), cfg, mesh)
-        state = jax.device_put(state, state_sh)
-        jstep = jax.jit(train_step, in_shardings=(state_sh, None),
-                        out_shardings=(state_sh, None))
     else:
-        jstep = jax.jit(train_step)
+        # one program builds the state in place: on a mesh, each device
+        # makes only its shard and no device ever holds the whole state
+        state = jax.jit(init_state, out_shardings=state_sh)(key)
+
+    # the step donates the state: the caller never holds two copies of it
+    jstep = jax.jit(train_step, in_shardings=(state_sh, None),
+                    out_shardings=(state_sh, None), donate_argnums=0)
 
     if cfg.frontend == "none":
         data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size, seq_len=seq,
@@ -118,6 +120,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

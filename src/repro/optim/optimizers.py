@@ -105,11 +105,13 @@ def _decode_moment(x, dtype: str, positive: bool = False):
 
 
 def adamw_init(params: PyTree, cfg: AdamWConfig) -> OptState:
-    zeros = jax.tree.map(
-        lambda p: _encode_moment(jnp.zeros(p.shape, jnp.float32),
-                                 cfg.moment_dtype), params)
-    return OptState(step=jnp.zeros((), jnp.int32), mu=zeros,
-                    nu=jax.tree.map(lambda z: z, zeros))
+    # mu and nu get buffers of their own: a train step that donates the
+    # state may not be handed one buffer twice
+    def zeros():
+        return jax.tree.map(
+            lambda p: _encode_moment(jnp.zeros(p.shape, jnp.float32),
+                                     cfg.moment_dtype), params)
+    return OptState(step=jnp.zeros((), jnp.int32), mu=zeros(), nu=zeros())
 
 
 def global_norm(tree: PyTree) -> jax.Array:

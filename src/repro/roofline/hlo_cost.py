@@ -484,23 +484,3 @@ class HloCostModel:
 def analyze(hlo_text: str) -> Cost:
     return HloCostModel(hlo_text).total()
 
-
-def xla_cost_dict(raw) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns one properties dict; jax 0.4.3x returns a *list* of
-    per-program dicts (usually length 1).  Merge by summing shared keys so
-    callers can always ``.get("flops")``.
-    """
-    if isinstance(raw, dict):
-        return raw
-    if not raw:
-        return {}
-    merged: dict = {}
-    for d in raw:
-        for k, v in d.items():
-            if isinstance(v, (int, float)):
-                merged[k] = merged.get(k, 0.0) + v
-            else:
-                merged.setdefault(k, v)
-    return merged

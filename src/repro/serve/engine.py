@@ -97,11 +97,12 @@ class SlotServer:
 
     def _decode_impl(self, params, tokens, pos, caches, active):
         """One decode step over all slots (per-slot positions); inactive
-        slots still compute (static shapes) but their outputs are ignored."""
+        slots still compute (static shapes) but their outputs are ignored.
+        Returns (next tokens [B], logits [B,V], new caches)."""
         logits, new_caches = transformer.decode_step(
             params, self.cfg, tokens, pos, caches)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return nxt, new_caches
+        return nxt, logits, new_caches
 
     # -- public API -----------------------------------------------------------------
 
@@ -146,7 +147,7 @@ class SlotServer:
         self._admit()
         if not self.active.any():
             return 0
-        nxt, self.caches = self._decode(
+        nxt, _, self.caches = self._decode(
             self.params, self._last, jnp.asarray(self.pos),
             self.caches, jnp.asarray(self.active))
         nxt_np = np.asarray(nxt)

@@ -10,7 +10,7 @@ from repro.core.types import DeviceSpec
 from repro.core.workloads import (decode_step_trace, prefill_trace,
                                   train_step_trace)
 from repro.roofline.hlo import collective_bytes
-from repro.roofline.hlo_cost import analyze, xla_cost_dict
+from repro.roofline.hlo_cost import analyze
 
 
 def test_analyzer_counts_scan_trips():
@@ -29,7 +29,7 @@ def test_analyzer_counts_scan_trips():
     expected = 7 * 2 * 64 ** 3
     assert expected <= cost.flops <= 1.05 * expected
     # XLA's own analysis counts the body once — the bug we correct
-    xla = float(xla_cost_dict(comp.cost_analysis()).get("flops", 0.0))
+    xla = float(comp.cost_analysis().get("flops", 0.0))
     assert xla < 0.5 * expected
 
 
