@@ -30,6 +30,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.configs.registry import get_config  # noqa: E402
 from repro.kernels.atom_matmul.ops import atom_matmul  # noqa: E402
 from repro.kernels.atom_matmul.ref import matmul_ref  # noqa: E402
@@ -58,28 +59,6 @@ KERNEL_TOL = 1e-2
 SERVE_TOL = 0.15
 # Sharded against one-chip training: max |loss difference| per step.
 LOSS_TOL = 2e-2
-COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
-
-
-class CompileClock:
-    """Sums JAX's trace, lowering and compile durations while active."""
-
-    def __init__(self):
-        self.seconds = 0.0
-
-    def _listen(self, event, duration, **_):
-        if event in COMPILE_EVENTS:
-            self.seconds += duration
-
-    def __enter__(self):
-        jax.monitoring.register_event_duration_secs_listener(self._listen)
-        return self
-
-    def __exit__(self, *exc):
-        jax.monitoring.unregister_event_duration_listener(self._listen)
-        return False
 
 
 def _check_close(name, out, ref, abs_ref):
@@ -184,15 +163,15 @@ def run_serve(cfg, *, slots: int = 8, max_len: int = 512,
     # eos_id=-1: no token ends a request early, so each yields max_new
     sc = ServeConfig(max_slots=slots, max_len=max_len,
                      max_new_tokens=max_new, eos_id=-1)
-    with CompileClock() as cc:
-        params, done, served, wall = _serve(cfg, prompts, sc, seed,
-                                            check_steps)
+    compiled = obs.tracer().compile_seconds
+    params, done, served, wall = _serve(cfg, prompts, sc, seed, check_steps)
+    compiled = obs.tracer().compile_seconds - compiled
     gc.collect()            # the recorders and the server form a cycle
     n_tok = sum(len(r.output) for r in done)
     print(f"[serve] {ARCH}: {len(done)}/{n_requests} requests, {n_tok} "
           f"tokens, {slots} slots, max_len {max_len}, prompt lengths "
           f"{sorted(set(prompt_lens))}, wall {wall:.2f}s, of it compile "
-          f"{cc.seconds:.2f}s", flush=True)
+          f"{compiled:.2f}s", flush=True)
     if len(done) != n_requests or any(len(r.output) != max_new for r in done):
         raise AssertionError("a request did not finish with "
                              f"{max_new} tokens")
@@ -238,13 +217,14 @@ def run_train(cfg, *, steps: int = 4, batch: int = 4, seq: int = 1024,
     print(f"[train] {where}: batch {batch} x seq {seq}, remat={tc.remat} "
           f"moment_dtype={tc.moment_dtype} n_micro={tc.n_micro}", flush=True)
     t0 = time.perf_counter()
-    with CompileClock() as cc:
-        state, losses = train(cfg, steps=steps, batch=batch, seq=seq, tc=tc,
-                              mesh=mesh, seed=seed, log_every=1)
+    compiled = obs.tracer().compile_seconds
+    state, losses = train(cfg, steps=steps, batch=batch, seq=seq, tc=tc,
+                          mesh=mesh, seed=seed, log_every=1)
+    compiled = obs.tracer().compile_seconds - compiled
     del state
     gc.collect()
     print(f"[train] losses {losses}; wall {time.perf_counter() - t0:.2f}s, "
-          f"of it compile {cc.seconds:.2f}s", flush=True)
+          f"of it compile {compiled:.2f}s", flush=True)
     if not all(math.isfinite(x) for x in losses):
         raise AssertionError("non-finite train loss")
     return losses

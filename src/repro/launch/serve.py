@@ -30,28 +30,50 @@ from repro.configs.registry import ARCH_IDS, get_config
 def serve(cfg, *, n_requests: int = 16, max_slots: int = 4,
           max_len: int = 128, max_new: int = 16, seed: int = 0,
           verbose: bool = True):
+    """Serve ``n_requests`` random prompts, all submitted at once; returns
+    the finished requests and ``latency_stats`` of them.  Every prompt
+    length is served once first, so that no compilation is timed."""
     # deferred: the --ctl-state-dir submit path must not pay (or require)
     # the jax import just to drop a spec file in the daemon's inbox
     from repro.serve.engine import ServeConfig, SlotServer
 
     rng = np.random.default_rng(seed)
-    t0 = time.time()
+    prompts = [rng.integers(2, cfg.vocab_size, int(rng.integers(
+        4, max_len // 2))).astype(np.int32) for _ in range(n_requests)]
     srv = SlotServer(cfg, serve_cfg=ServeConfig(
         max_slots=max_slots, max_len=max_len, max_new_tokens=max_new),
-        seed=seed, clock=lambda: time.time() - t0)
-    for _ in range(n_requests):
-        plen = int(rng.integers(4, max_len // 2))
-        srv.submit(rng.integers(2, cfg.vocab_size, plen).astype(np.int32),
-                   max_new_tokens=max_new)
+        seed=seed)
+    for n in sorted({len(p) for p in prompts}):
+        srv.submit(np.full(n, 2, np.int32), max_new_tokens=2)
+    srv.run_until_drained()
+    srv.done.clear()
+    t0 = time.perf_counter()
+    for p in prompts:
+        srv.submit(p, max_new_tokens=max_new)
     done = srv.run_until_drained()
-    lats = srv.latencies()
+    wall = time.perf_counter() - t0
+    stats = latency_stats(done)
     if verbose:
         toks = sum(len(r.output) for r in done)
-        wall = time.time() - t0
         print(f"[serve] {len(done)} requests, {toks} tokens in {wall:.2f}s "
-              f"({toks/wall:.1f} tok/s) p50={np.percentile(lats,50)*1e3:.0f}ms "
-              f"p99={np.percentile(lats,99)*1e3:.0f}ms")
-    return done, lats
+              f"({toks/wall:.1f} tok/s); p50/p99 ms: " + ", ".join(
+                  f"{k} {v[0]*1e3:.1f}/{v[1]*1e3:.1f}"
+                  for k, v in stats.items()))
+    return done, stats
+
+
+def latency_stats(done) -> dict:
+    """p50 and p99 in seconds, from the engine's own stamps, of the wait
+    for a slot (``queue``), the time to first token (``ttft``) and the
+    gaps between a request's tokens (``tpot``); a name with no values is
+    left out."""
+    values = {
+        "queue": [r.t_admit - r.t_submit for r in done],
+        "ttft": [r.t_first_token - r.t_submit for r in done],
+        "tpot": [b - a for r in done
+                 for a, b in zip(r.token_times, r.token_times[1:])]}
+    return {k: (float(np.percentile(v, 50)), float(np.percentile(v, 99)))
+            for k, v in values.items() if v}
 
 
 def submit_to_ctl(args) -> str:

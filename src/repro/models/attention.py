@@ -23,7 +23,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import KeyGen, einsum, fan_in_init, normal_init, zeros_init
+from repro.models.common import (KeyGen, einsum, fan_in_init, normal_init, scoped,
+                                 zeros_init)
 from repro.models.layers import apply_rope
 
 
@@ -46,6 +47,7 @@ def init_attention(keys: KeyGen, d: int, n_heads: int, n_kv: int, head_dim: int,
     return p
 
 
+@scoped("attention")
 def qkv_project(params, x, positions, rope_theta: float, use_rope: bool = True):
     """x: [B,S,D] -> q [B,S,Hq,Dh], k,v [B,S,Hkv,Dh] (RoPE applied)."""
     q = einsum("btd,dhk->bthk", x, params["wq"])
@@ -61,6 +63,7 @@ def qkv_project(params, x, positions, rope_theta: float, use_rope: bool = True):
     return q, k, v
 
 
+@scoped("attention")
 def out_project(params, attn_out):
     """attn_out: [B,S,Hq,Dh] -> [B,S,D]."""
     return einsum("bthk,hkd->btd", attn_out, params["wo"])
@@ -138,6 +141,7 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths), pad
 
 
+@scoped("attention")
 def blocked_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       block_q: int = 512, block_kv: int = 512,
                       block_skip: bool = True, q_offset: int = 0):
@@ -244,6 +248,7 @@ def blocked_attention(q, k, v, *, causal: bool = True, window: int = 0,
 # Decode attention (single new token against a KV cache)
 # ---------------------------------------------------------------------------
 
+@scoped("attention")
 def decode_attention(q, k_cache, v_cache, cur_len, *, window: int = 0):
     """q: [B,Hq,Dh]; caches: [B,Smax,Hkv,Dh]; cur_len: int [] or per-slot
     [B] (tokens valid per batch row — continuous batching).
@@ -267,6 +272,7 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window: int = 0):
     return o.reshape(B, Hq, Dh).astype(q.dtype)
 
 
+@scoped("kv_cache")
 def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, *, window: int = 0):
     """Insert k/v at ``pos`` ([B,1,Hkv,Dh] or [B,S,Hkv,Dh] prefill).
 

@@ -7,6 +7,7 @@ paths to logical dimension names which ``sharding.py`` resolves to mesh axes.
 """
 from __future__ import annotations
 
+import functools
 import math
 import re
 from typing import Any, Callable
@@ -16,6 +17,19 @@ import jax.numpy as jnp
 import numpy as np
 
 PyTree = Any
+
+
+def scoped(name: str):
+    """Decorator: the function's operations under ``jax.named_scope(name)``,
+    so that compiled ops and profiler traces name the layer they belong
+    to.  Names change the HLO's metadata only."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 def dtype_of(name: str):

@@ -4,7 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import KeyGen, dot, fan_in_init, normal_init, ones_init, zeros_init
+from repro.models.common import (KeyGen, dot, fan_in_init, normal_init, ones_init,
+                                 scoped, zeros_init)
 
 
 # ---------------------------------------------------------------------------
@@ -21,6 +22,7 @@ def init_norm(keys: KeyGen, d: int, kind: str, dtype):
     raise ValueError(kind)
 
 
+@scoped("norm")
 def apply_norm(params, x, kind: str, eps: float = 1e-6):
     xf = x.astype(jnp.float32)
     if kind == "rmsnorm":
@@ -45,6 +47,7 @@ def init_mlp(keys: KeyGen, d: int, f: int, activation: str, dtype):
     return p
 
 
+@scoped("mlp")
 def apply_mlp(params, x, activation: str):
     h = dot(x, params["wi"])
     if activation == "swiglu":
@@ -93,6 +96,7 @@ def init_embed(keys: KeyGen, vocab: int, d: int, dtype, with_pos: int = 0):
     return p
 
 
+@scoped("embed")
 def embed_tokens(params, tokens):
     return jnp.take(params["tok"], tokens, axis=0)
 
@@ -101,6 +105,7 @@ def init_head(keys: KeyGen, d: int, vocab: int, dtype):
     return {"w": normal_init(keys(), (d, vocab), dtype)}
 
 
+@scoped("head")
 def apply_head(params, x, embed_params=None, softcap: float = 0.0):
     """LM head; uses tied embedding transpose when ``params`` is None."""
     from repro.models.common import _safe_dot

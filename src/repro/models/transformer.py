@@ -25,7 +25,7 @@ from repro.configs.base import ArchConfig
 from repro.models import attention as attn_lib
 from repro.models import rglru as rglru_lib
 from repro.models import ssm as ssm_lib
-from repro.models.common import KeyGen, dtype_of
+from repro.models.common import KeyGen, dtype_of, scoped
 from repro.models.layers import (apply_head, apply_mlp, apply_norm, embed_tokens,
                                  init_embed, init_head, init_mlp, init_norm)
 from repro.models.moe import apply_moe, init_moe
@@ -109,6 +109,7 @@ def init_lm(cfg: ArchConfig, key) -> PyTree:
 # Forward (train / prefill hidden states)
 # ---------------------------------------------------------------------------
 
+@scoped("block")
 def _apply_block(bp, x, cfg: ArchConfig, kind: str, positions, *,
                  block_skip: bool = True, attn_block: int = 512,
                  mlstm_chunk: int = 256):
@@ -184,7 +185,9 @@ def forward(params, cfg: ArchConfig, tokens=None, *, input_embeds=None,
 
     zero = jnp.zeros((), jnp.float32)
     if params.get("blocks"):
-        (x, lb, zl), _ = jax.lax.scan(body, (x, zero, zero), params["blocks"])
+        with jax.named_scope("layers"):
+            (x, lb, zl), _ = jax.lax.scan(body, (x, zero, zero),
+                                          params["blocks"])
     else:
         lb = zl = zero
     for i in sorted(params.get("rem", {})):
@@ -289,6 +292,7 @@ def init_caches(cfg: ArchConfig, batch: int, max_len: int):
     return {"groups": groups, "rem": rem}
 
 
+@scoped("block")
 def _decode_block(bp, x, cfg, kind, pos_scalar, cache):
     """x: [B,1,D]; cache: this block's state slice.  Returns (x, new_cache)."""
     window = _window_for(cfg, kind)
@@ -329,6 +333,7 @@ def _decode_block(bp, x, cfg, kind, pos_scalar, cache):
     raise ValueError(kind)
 
 
+@scoped("block")
 def _prefill_block(bp, x, cfg, kind, positions, cache, *, block_skip, attn_block):
     """Prompt-length block application that also fills this block's cache."""
     window = _window_for(cfg, kind)
@@ -396,7 +401,9 @@ def decode_step(params, cfg: ArchConfig, token, pos_scalar, caches, *,
 
     new_groups = caches["groups"]
     if params.get("blocks"):
-        x, new_groups = jax.lax.scan(group_body, x, (params["blocks"], caches["groups"]))
+        with jax.named_scope("layers"):
+            x, new_groups = jax.lax.scan(
+                group_body, x, (params["blocks"], caches["groups"]))
     new_rem = {}
     for i in sorted(params.get("rem", {})):
         x, new_rem[i] = _decode_block(
@@ -428,7 +435,9 @@ def prefill(params, cfg: ArchConfig, tokens, *, input_embeds=None,
 
     new_groups = caches["groups"]
     if params.get("blocks"):
-        x, new_groups = jax.lax.scan(group_body, x, (params["blocks"], caches["groups"]))
+        with jax.named_scope("layers"):
+            x, new_groups = jax.lax.scan(
+                group_body, x, (params["blocks"], caches["groups"]))
     new_rem = {}
     for i in sorted(params.get("rem", {})):
         x, new_rem[i] = _prefill_block(

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.models.common import scoped
 from repro.models.registry import init_model, train_loss
 from repro.optim.optimizers import (AdamWConfig, OptState, adamw_init,
                                     adamw_update, dequantize, quantize)
@@ -93,6 +94,7 @@ def make_train_step(cfg: ArchConfig, tc: TrainConfig = TrainConfig()):
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
+    @scoped("train_step")
     def train_step(state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         params = state.params
         if tc.n_micro > 1:

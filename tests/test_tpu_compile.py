@@ -91,3 +91,55 @@ def test_olmo_1b_decode_step_compiles(one_chip, monkeypatch):
     compiled = jax.jit(step).lower(params, tok, tok, caches).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 16 * 2 ** 30
+
+
+def test_decode_scopes_name_the_recorded_chat_trace(one_chip, monkeypatch,
+                                                    tmp_path):
+    """The named scopes of ``SlotServer._decode_impl`` compiled at the chat
+    cell's shapes name every operation of the decode step traced on a v5e
+    chip (``chipbench/tests/data``, recorded before the scopes existed: the
+    scopes change metadata only, so the operations and their names are the
+    same), and put the step's time into its layers."""
+    import gzip
+    import types
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    monkeypatch.syspath_prepend(str(root))
+    monkeypatch.setenv("REPRO_SAFE_DOT", "0")
+    from chipbench import scopes, trace, weights
+    from repro.serve.engine import SlotServer
+
+    arch = weights.arch_config(weights.load_config("olmo-1b"))
+    on_chip = functools.partial(jax.tree.map,
+                                lambda x: _spec(one_chip, x.shape, x.dtype))
+    params = on_chip(weights._unflatten({
+        p: {} if s is None else s for p, s in weights.layout(arch).items()}))
+    caches = on_chip(jax.eval_shape(
+        lambda: transformer.init_caches(arch, 16, 2048)))
+    srv = SlotServer.__new__(SlotServer)
+    srv.cfg = arch
+    vec = _spec(one_chip, (16,), jnp.int32)
+    hlo = jax.jit(srv._decode_impl).lower(
+        params, vec, vec, caches, _spec(one_chip, (16,), jnp.bool_)
+    ).compile().as_text()
+    sc = scopes.hlo_scopes(hlo)
+    path = tmp_path / "chat.xplane.pb"
+    path.write_bytes(gzip.decompress(
+        (root / "chipbench/tests/data/chat.xplane.pb.gz").read_bytes()))
+    run = types.SimpleNamespace(reduced=trace.read(str(path), set()))
+    whole = scopes.decode_ms(run, lambda p: True, sc)
+    cache_io = scopes.decode_ms(
+        run, lambda p: "layers" in p and "block" not in p, sc)
+    attention = scopes.decode_ms(run, lambda p: "attention" in p, sc)
+    assert whole == pytest.approx(35.98, abs=0.01)      # every op named
+    assert cache_io == pytest.approx(26.68, abs=0.01)
+    assert attention == pytest.approx(6.72, abs=0.01)
+    assert "/block/attention/" in sc["%fusion.147 = bf16[16,16,128]"]
+    # a trace of the program with its scopes: the compiled step names each
+    # traced operation as the trace itself does
+    path = tmp_path / "chat_scoped.xplane.pb"
+    path.write_bytes(gzip.decompress(
+        (root / "tests/data/chat_scoped.xplane.pb.gz").read_bytes()))
+    traced = scopes.xplane_scopes(str(path))
+    assert traced and {k: sc.get(k) for k in traced} == traced
