@@ -249,13 +249,18 @@ def blocked_attention(q, k, v, *, causal: bool = True, window: int = 0,
 # ---------------------------------------------------------------------------
 
 @scoped("attention")
-def decode_attention(q, k_cache, v_cache, cur_len, *, window: int = 0):
-    """q: [B,Hq,Dh]; caches: [B,Smax,Hkv,Dh]; cur_len: int [] or per-slot
-    [B] (tokens valid per batch row — continuous batching).
+def decode_attention(q, k_cache, v_cache, cur_len, *, window: int = 0,
+                     layer=None):
+    """q: [B,Hq,Dh]; caches: [B,Smax,Hkv,Dh], or the stacked [G,B,Smax,Hkv,Dh]
+    of a layer scan with ``layer`` selecting one; cur_len: int [] or
+    per-slot [B] (tokens valid per batch row — continuous batching).
 
     For sliding-window layers the cache is a ring buffer of size ``window``
     and every slot < min(cur_len, window) is valid.
     """
+    if layer is not None:
+        k_cache = jax.lax.dynamic_index_in_dim(k_cache, layer, 0, keepdims=False)
+        v_cache = jax.lax.dynamic_index_in_dim(v_cache, layer, 0, keepdims=False)
     B, Hq, Dh = q.shape
     Smax, Hk = k_cache.shape[1], k_cache.shape[2]
     G = Hq // Hk
@@ -273,23 +278,28 @@ def decode_attention(q, k_cache, v_cache, cur_len, *, window: int = 0):
 
 
 @scoped("kv_cache")
-def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, *, window: int = 0):
+def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, *, window: int = 0,
+                    layer=None):
     """Insert k/v at ``pos`` ([B,1,Hkv,Dh] or [B,S,Hkv,Dh] prefill).
 
     ``pos`` may be a scalar (shared position) or [B] (per-slot positions —
-    continuous batching; requires S == 1).
+    continuous batching; requires S == 1).  With ``layer`` the caches are
+    the stacked [G,B,Smax,Hkv,Dh] of a layer scan, carried through it: only
+    the B new rows are written, at ``[layer, b, pos[b]]``, so a donated
+    cache is updated in place and never copied (decode only).
     """
     # never let the insert promote the cache (a f32 update would carry the
     # WHOLE cache in f32 through the layer scan — 2x HBM + convert traffic)
     k_new = k_new.astype(k_cache.dtype)
     v_new = v_new.astype(v_cache.dtype)
     pos = jnp.asarray(pos)
-    if pos.ndim == 1:
+    if pos.ndim == 1 or layer is not None:
         assert k_new.shape[1] == 1, "per-slot insert is decode-only"
         B = k_new.shape[0]
-        idx = (pos % window) if window else pos
-        k_cache = k_cache.at[jnp.arange(B), idx].set(k_new[:, 0])
-        v_cache = v_cache.at[jnp.arange(B), idx].set(v_new[:, 0])
+        idx = jnp.broadcast_to((pos % window) if window else pos, (B,))
+        at = (jnp.arange(B), idx) if layer is None else (layer, jnp.arange(B), idx)
+        k_cache = k_cache.at[at].set(k_new[:, 0], unique_indices=True)
+        v_cache = v_cache.at[at].set(v_new[:, 0], unique_indices=True)
         return k_cache, v_cache
     if window:
         S = k_new.shape[1]

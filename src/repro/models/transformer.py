@@ -293,8 +293,10 @@ def init_caches(cfg: ArchConfig, batch: int, max_len: int):
 
 
 @scoped("block")
-def _decode_block(bp, x, cfg, kind, pos_scalar, cache):
-    """x: [B,1,D]; cache: this block's state slice.  Returns (x, new_cache)."""
+def _decode_block(bp, x, cfg, kind, pos_scalar, cache, layer=None):
+    """x: [B,1,D]; cache: this block's state slice or, for an attention
+    block given its ``layer``, the stacked K/V caches of its position in
+    the pattern.  Returns (x, new_cache)."""
     window = _window_for(cfg, kind)
     if kind == "attn":
         h = apply_norm(bp["ln1"], x, cfg.norm)
@@ -304,8 +306,9 @@ def _decode_block(bp, x, cfg, kind, pos_scalar, cache):
                      else jnp.broadcast_to(pos_arr, (B, 1)))
         q, k, v = attn_lib.qkv_project(bp["attn"], h, positions, cfg.rope_theta)
         kc, vc = attn_lib.update_kv_cache(
-            cache["k"], cache["v"], k, v, pos_scalar, window=window)
-        o = attn_lib.decode_attention(q[:, 0], kc, vc, pos_scalar + 1, window=window)
+            cache["k"], cache["v"], k, v, pos_scalar, window=window, layer=layer)
+        o = attn_lib.decode_attention(q[:, 0], kc, vc, pos_scalar + 1,
+                                      window=window, layer=layer)
         x = x + attn_lib.out_project(bp["attn"], o[:, None])
         h = apply_norm(bp["ln2"], x, cfg.norm)
         if cfg.moe is not None:
@@ -385,25 +388,42 @@ def decode_step(params, cfg: ArchConfig, token, pos_scalar, caches, *,
     ``pos_scalar`` may be a scalar (shared) or [B] per-slot positions
     (continuous batching).
 
+    The stacked K/V caches of the scanned attention layers ride in the
+    scan's carry, each layer writing only its new rows into them, so that
+    a donated cache is updated in place; the recurrent states, a few MB a
+    layer, are sliced out per layer and stacked again.
+
     Returns (logits [B,V] f32, new caches).
     """
     x = embed_inputs(params, cfg, token[:, None] if token is not None else None,
                      input_embeds)
     pat = layer_pattern(cfg)
+    groups = caches["groups"]
+    kv = {p: c for p, c in groups.items() if pat[int(p)] == "attn"}
+    states = {p: c for p, c in groups.items() if p not in kv}
 
-    def group_body(x, xs):
-        gp, cache_slices = xs
-        new_slices = {}
+    def group_body(carry, xs):
+        x, kv = carry
+        gp, state_slices, layer = xs
+        new_states = {}
         for pos, kind in enumerate(pat):
-            x, new_slices[str(pos)] = _decode_block(
-                gp[str(pos)], x, cfg, kind, pos_scalar, cache_slices[str(pos)])
-        return x, new_slices
+            p = str(pos)
+            if p in kv:
+                x, kv[p] = _decode_block(gp[p], x, cfg, kind, pos_scalar,
+                                         kv[p], layer)
+            else:
+                x, new_states[p] = _decode_block(gp[p], x, cfg, kind, pos_scalar,
+                                                 state_slices[p])
+        return (x, kv), new_states
 
-    new_groups = caches["groups"]
+    new_groups = groups
     if params.get("blocks"):
+        n_groups = cfg.n_layers // len(pat)
         with jax.named_scope("layers"):
-            x, new_groups = jax.lax.scan(
-                group_body, x, (params["blocks"], caches["groups"]))
+            (x, kv), states = jax.lax.scan(
+                group_body, (x, kv),
+                (params["blocks"], states, jnp.arange(n_groups)))
+        new_groups = {**kv, **states}
     new_rem = {}
     for i in sorted(params.get("rem", {})):
         x, new_rem[i] = _decode_block(

@@ -93,8 +93,11 @@ class SlotServer:
         self._rid = itertools.count()
         self._last = jnp.zeros(B, jnp.int32)        # last sampled token
 
-        self._prefill = jax.jit(self._prefill_impl)
-        self._decode = jax.jit(self._decode_impl)
+        # the caches (argument 2 of prefill, 3 of decode) are donated: each
+        # step updates them in place, and the engine keeps only the caches
+        # a step returns
+        self._prefill = jax.jit(self._prefill_impl, donate_argnums=2)
+        self._decode = jax.jit(self._decode_impl, donate_argnums=3)
 
     # -- jitted compute ----------------------------------------------------------
 

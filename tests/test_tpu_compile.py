@@ -9,6 +9,8 @@ The topology is described only inside the module fixture: one process at a
 time may load the TPU library, and every test worker imports this file.
 """
 import functools
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -93,22 +95,15 @@ def test_olmo_1b_decode_step_compiles(one_chip, monkeypatch):
     assert mem.argument_size_in_bytes < 16 * 2 ** 30
 
 
-def test_decode_scopes_name_the_recorded_chat_trace(one_chip, monkeypatch,
-                                                    tmp_path):
-    """The named scopes of ``SlotServer._decode_impl`` compiled at the chat
-    cell's shapes name every operation of the decode step traced on a v5e
-    chip (``chipbench/tests/data``, recorded before the scopes existed: the
-    scopes change metadata only, so the operations and their names are the
-    same), and put the step's time into its layers."""
-    import gzip
-    import types
-    from pathlib import Path
-
+def _chat_step(one_chip, monkeypatch):
+    """olmo-1b at the chat cell's shapes (16 slots of 2048) on the described
+    chip: a ``SlotServer`` that holds only its configuration, and the
+    shapes of its parameters and caches."""
     root = Path(__file__).resolve().parents[1]
     monkeypatch.syspath_prepend(str(root))
     monkeypatch.setenv("REPRO_SAFE_DOT", "0")
-    from chipbench import scopes, trace, weights
-    from repro.serve.engine import SlotServer
+    from chipbench import weights
+    from repro.serve.engine import ServeConfig, SlotServer
 
     arch = weights.arch_config(weights.load_config("olmo-1b"))
     on_chip = functools.partial(jax.tree.map,
@@ -118,28 +113,112 @@ def test_decode_scopes_name_the_recorded_chat_trace(one_chip, monkeypatch,
     caches = on_chip(jax.eval_shape(
         lambda: transformer.init_caches(arch, 16, 2048)))
     srv = SlotServer.__new__(SlotServer)
-    srv.cfg = arch
+    srv.cfg, srv.sc = arch, ServeConfig(max_slots=16, max_len=2048)
+    return srv, params, caches
+
+
+def _decode_args(one_chip, params, caches):
     vec = _spec(one_chip, (16,), jnp.int32)
+    return params, vec, vec, caches, _spec(one_chip, (16,), jnp.bool_)
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%[\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%[\w.\-]+ = (\w+\[[\d,]*\])\S* ([\w-]+)\(")
+
+
+def _scheduled_ops(hlo: str) -> list:
+    """(shape, opcode) of each instruction outside fused computations: the
+    operations the chip runs and the buffers they write."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.\-]+)", hlo))
+    comp, ops = None, []
+    for line in hlo.splitlines():
+        c = _COMPUTATION.match(line)
+        if c:
+            comp = c.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if m and comp not in fused:
+            ops.append(m.groups())
+    return ops
+
+
+def _hlo_shape(x) -> str:
+    return f"bf16[{','.join(map(str, x))}]"
+
+
+def test_chat_decode_step_updates_the_cache_in_place(one_chip, monkeypatch):
+    """The decode step with its caches donated, as ``SlotServer`` runs it,
+    writes each step's rows into the stacked caches in place: it aliases
+    them whole, needs almost no scratch memory, and neither slices a
+    layer's cache out (``bf16[16,2048,16,128]``) nor copies the stack."""
+    srv, params, caches = _chat_step(one_chip, monkeypatch)
+    compiled = jax.jit(srv._decode_impl, donate_argnames="caches").lower(
+        *_decode_args(one_chip, params, caches)).compile()
+    mem = compiled.memory_analysis()
+    stacked = caches["groups"]["0"]["k"]
+    assert mem.alias_size_in_bytes == 2 * stacked.size * stacked.dtype.itemsize
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+    ops = _scheduled_ops(compiled.as_text())
+    assert ops
+    layer = _hlo_shape(stacked.shape[1:])
+    assert not [op for shape, op in ops if shape == layer
+                and op not in ("parameter", "get-tuple-element")]
+    assert (_hlo_shape(stacked.shape), "copy") not in ops
+
+
+@pytest.mark.parametrize("prompt", [64, 1024])
+def test_chat_prefill_writes_the_slot_in_place(one_chip, monkeypatch, prompt):
+    """The batch-1 prefill with its caches donated writes the new slot into
+    the stacked caches without copying them."""
+    srv, params, caches = _chat_step(one_chip, monkeypatch)
+    compiled = jax.jit(srv._prefill_impl, donate_argnames="caches").lower(
+        params, _spec(one_chip, (1, prompt), jnp.int32), caches, 3).compile()
+    stacked = caches["groups"]["0"]["k"]
+    assert compiled.memory_analysis().alias_size_in_bytes == \
+        2 * stacked.size * stacked.dtype.itemsize
+    ops = _scheduled_ops(compiled.as_text())
+    assert ops and (_hlo_shape(stacked.shape), "copy") not in ops
+
+
+def test_decode_scopes_name_the_recorded_chat_trace(one_chip, monkeypatch,
+                                                    tmp_path):
+    """The named scopes of ``SlotServer._decode_impl`` compiled at the chat
+    cell's shapes, without donation as ``chipbench/scopes.py`` compiles
+    it, name every operation of the decode step traced on a v5e chip with
+    the cache updated in place (``tests/data/chat_inplace.xplane.pb.gz``,
+    half a second of the chat cell), each as the trace itself does, and put
+    the step's time into its layers."""
+    import gzip
+    import types
+
+    srv, params, caches = _chat_step(one_chip, monkeypatch)
+    from chipbench import scopes, trace
+
     hlo = jax.jit(srv._decode_impl).lower(
-        params, vec, vec, caches, _spec(one_chip, (16,), jnp.bool_)
-    ).compile().as_text()
+        *_decode_args(one_chip, params, caches)).compile().as_text()
     sc = scopes.hlo_scopes(hlo)
-    path = tmp_path / "chat.xplane.pb"
+    root = Path(__file__).resolve().parents[1]
+    path = tmp_path / "chat_inplace.xplane.pb"
     path.write_bytes(gzip.decompress(
-        (root / "chipbench/tests/data/chat.xplane.pb.gz").read_bytes()))
-    run = types.SimpleNamespace(reduced=trace.read(str(path), set()))
-    whole = scopes.decode_ms(run, lambda p: True, sc)
-    cache_io = scopes.decode_ms(
-        run, lambda p: "layers" in p and "block" not in p, sc)
-    attention = scopes.decode_ms(run, lambda p: "attention" in p, sc)
-    assert whole == pytest.approx(35.98, abs=0.01)      # every op named
-    assert cache_io == pytest.approx(26.68, abs=0.01)
-    assert attention == pytest.approx(6.72, abs=0.01)
-    assert "/block/attention/" in sc["%fusion.147 = bf16[16,16,128]"]
-    # a trace of the program with its scopes: the compiled step names each
-    # traced operation as the trace itself does
-    path = tmp_path / "chat_scoped.xplane.pb"
-    path.write_bytes(gzip.decompress(
-        (root / "tests/data/chat_scoped.xplane.pb.gz").read_bytes()))
+        (root / "tests/data/chat_inplace.xplane.pb.gz").read_bytes()))
     traced = scopes.xplane_scopes(str(path))
     assert traced and {k: sc.get(k) for k in traced} == traced
+    run = types.SimpleNamespace(reduced=trace.read(str(path), set()))
+
+    def ms(keep):
+        return scopes.decode_ms(run, keep, sc)
+    assert ms(lambda p: True) == pytest.approx(9.88, abs=0.01)  # all named
+    assert ms(lambda p: "attention" in p) == pytest.approx(6.70, abs=0.01)
+    assert ms(lambda p: "kv_cache" in p) == pytest.approx(0.067, abs=0.001)
+    # no operation slices a layer's cache out any more, and the only ones
+    # that write the stacked caches are the row inserts under kv_cache
+    assert not [k for k in traced if k.endswith("= bf16[16,2048,16,128]")]
+    whole = [v for k, v in traced.items()
+             if k.endswith("= bf16[16,16,2048,16,128]")]
+    assert whole and all("/kv_cache/" in v for v in whole)
+    # what the layer scan does outside any block is now only the slicing of
+    # each layer's q, k and v weights (0.60 ms in the parent's trace, beside
+    # 26.08 ms of slicing and writing back the caches)
+    assert ms(lambda p: "layers" in p and "block" not in p) == \
+        pytest.approx(0.59, abs=0.01)
